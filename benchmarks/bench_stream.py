@@ -176,7 +176,12 @@ def _sharded_child(seed: int = 0, repeats: int = 2) -> list[dict]:
 def _sharded_sweep(results, rows, device_counts, seed: int = 0):
     """Spawn one forced-host-platform subprocess per device count and
     merge its rows; asserts the multi-device runs match single-placement
-    exactly (the ISSUE 5 acceptance bound for int-valued payloads)."""
+    exactly (the bound for int-valued payloads).
+
+    CPU hosts only (``run`` skips it elsewhere): the children force the
+    host platform, and on an accelerator host the parent already holds
+    the chip, so they would record CPU rows under the sweep's names.
+    There the sharded comparison is ``chip_smoke.py --chips 4``."""
     env_counts = os.environ.get("REPRO_BENCH_DEVICE_COUNTS")
     if env_counts:
         device_counts = tuple(int(x) for x in env_counts.split(","))
@@ -859,8 +864,13 @@ def run(batches=(16, 64, 256), n_batches: int = 30, seed: int = 0,
             record("retailer_cofactor_degree_m", "fivm", batch, 10,
                    backend, tps_f, tps_p, pstats)
 
-    # -- sharded scan carry: per-device-count subprocess sweep -------------
-    if os.environ.get("REPRO_BENCH_SKIP_SHARDED") != "1":
+    # -- sharded scan carry: per-device-count subprocess sweep (CPU) -------
+    import jax
+
+    if jax.default_backend() != "cpu":
+        print("# sharded sweep refused: its children force CPU devices; "
+              "run `python chip_smoke.py --chips 4` on chips")
+    elif os.environ.get("REPRO_BENCH_SKIP_SHARDED") != "1":
         _sharded_sweep(results, rows, DEVICE_COUNTS, seed=seed)
 
     # -- segmented stream pipeline: two-deep admit/run overlap -------------

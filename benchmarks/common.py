@@ -7,6 +7,7 @@ reproduction target (EXPERIMENTS.md cites both).
 """
 from __future__ import annotations
 
+import os
 import time
 
 import jax
@@ -14,6 +15,23 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import DenseRelation, Query, chain, sum_ring
+
+#: in-checkout persistent compile cache, used when JAX_COMPILATION_CACHE_DIR
+#: is unset (the path is part of the cache key, so it never moves)
+COMPILE_CACHE_DIR = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), ".jax_cache")
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compile cache for an entry-point script.
+    JAX itself reads ``JAX_COMPILATION_CACHE_DIR`` when it is set;
+    otherwise the cache lives at the fixed :data:`COMPILE_CACHE_DIR`.
+    Returns the directory in use.  The engine library never touches this
+    setting."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    return jax.config.jax_compilation_cache_dir
+
 
 # ---------------------------------------------------------------------------
 # Retailer-like snowflake (scaled-down dictionary domains)

@@ -14,6 +14,7 @@ from . import (bench_batch_size, bench_cofactor, bench_factorized_payloads,
                bench_grad_compression, bench_kernels, bench_matrix_chain,
                bench_serve, bench_stream, bench_sum_aggregates,
                bench_triangle, bench_view_counts, roofline)
+from .common import use_compile_cache
 
 
 def main() -> None:
@@ -21,6 +22,7 @@ def main() -> None:
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
+    use_compile_cache()
 
     sections = [
         ("stream executor (fused vs per-call; BENCH_stream.json)",

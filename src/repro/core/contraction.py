@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from .relations import COOUpdate, DenseRelation
-from .rings import Payload, Ring
+from .rings import EXACT, Payload, Ring
 
 _KEY_LETTERS = string.ascii_lowercase
 _PAY_LETTERS = string.ascii_uppercase
@@ -63,7 +63,8 @@ def _einsum_plan(mul_terms, a_key: str, b_key: str, o_key: str):
 def _apply_plan(plan, a_payload: Payload, b_payload: Payload) -> dict:
     out: dict[str, jnp.ndarray] = {}
     for comp_out, comp_a, comp_b, spec, coef in plan:
-        term = jnp.einsum(spec, a_payload[comp_a], b_payload[comp_b])
+        term = jnp.einsum(spec, a_payload[comp_a], b_payload[comp_b],
+                          precision=EXACT)
         if coef != 1.0:
             term = term * coef
         out[comp_out] = out.get(comp_out, 0) + term

@@ -21,6 +21,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+#: precision of every ring contraction.  XLA:TPU's default runs an f32
+#: dot as one bf16 pass (8 significant bits), which rounds cofactor
+#: payloads; HIGHEST keeps f32 semantics.  CPU dots are f32 either way.
+EXACT = jax.lax.Precision.HIGHEST
+
 Payload = Any  # pytree: dict[str, jnp.ndarray]
 
 
@@ -123,7 +128,8 @@ class Ring:
             )
             x = jnp.broadcast_to(x, kshape + x.shape[nk:])
             y = jnp.broadcast_to(y, kshape + y.shape[nk:])
-            term = jnp.einsum(spec, x, y) * (t.coef if t.coef != 1.0 else 1.0)
+            term = jnp.einsum(spec, x, y, precision=EXACT) * (
+                t.coef if t.coef != 1.0 else 1.0)
             out[t.comp_out] = out.get(t.comp_out, 0) + term
         # fill in components never produced (stay zero)
         any_k = next(iter(out))

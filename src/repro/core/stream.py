@@ -770,7 +770,12 @@ class StreamExecutor:
                                    self.shard.replicate(tail) if tail
                                    else tail)
             _, xs, tail = prepared.placed
-        new_state = self.compiled(prepared)(state, xs, tail)
+            # trace under the mesh: the kernels see it and run per device
+            # (kernels.ring_scatter.per_device)
+            with jax.set_mesh(self.shard.mesh):
+                new_state = self.compiled(prepared)(state, xs, tail)
+        else:
+            new_state = self.compiled(prepared)(state, xs, tail)
         if update_engine:
             self.engine.set_state(new_state)
         return new_state

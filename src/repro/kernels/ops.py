@@ -25,7 +25,7 @@ from .flash_attention import flash_attention as _flash_pallas
 from .rank1_chain import matvec as _matvec_pallas
 from .rank1_chain import outer_accumulate as _outer_pallas
 from .ring_mul import ring_mul as _ring_mul_pallas
-from .segment_ring_sum import segment_ring_sum as _segsum_pallas
+from .ring_scatter import segment_ring_sum as _segsum_pallas
 
 
 def default_backend() -> str:

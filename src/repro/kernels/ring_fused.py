@@ -41,7 +41,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .ring_scatter import _iota_cols, tile_dedup
+from .ring_scatter import (EXACT, _col, _col_spec, _iota_cols, _row,
+                           _row_spec, per_device, tile_dedup)
 
 #: largest gathered-source plane (rows) a fused chain keeps whole in VMEM;
 #: chains gathering from bigger planes stay unfused (op-by-op fallback)
@@ -158,9 +159,9 @@ def chain_vmem_bytes(src_rows, width: int, *, block_s: int = BLOCK_S,
 # The megakernel
 # ---------------------------------------------------------------------------
 def _fused_kernel(*refs, block_s: int, n_src: int, spec):
-    out_ids_ref, vals_ref = refs[0], refs[1]
-    id_refs = refs[2:2 + n_src]
-    plane_refs = refs[2 + n_src:2 + 2 * n_src]
+    out_ids_ref, out_ids_row_ref, vals_ref = refs[0], refs[1], refs[2]
+    id_refs = refs[3:3 + n_src]
+    plane_refs = refs[3 + n_src:3 + 2 * n_src]
     view_ref, out_ref = refs[-2], refs[-1]
     si = pl.program_id(0)
     k = pl.program_id(1)
@@ -172,20 +173,19 @@ def _fused_kernel(*refs, block_s: int, n_src: int, spec):
     v = vals_ref[...].astype(jnp.float32)  # [bk, dp]
     bk = v.shape[0]
     for i in range(n_src):
-        ids = id_refs[i][...]  # [bk]
+        ids = id_refs[i][...]  # [bk, 1]
         plane = plane_refs[i][...].astype(jnp.float32)  # [Sg, dp] whole
-        onehot = (ids[:, None] == _iota_cols(bk, plane.shape[0])
-                  ).astype(jnp.float32)
+        onehot = (ids == _iota_cols(bk, plane.shape[0])).astype(jnp.float32)
         g = jax.lax.dot_general(
             onehot, plane, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)  # [bk, dp]
+            precision=EXACT, preferred_element_type=jnp.float32)  # [bk, dp]
         v = ring_mul_flat(v, g, spec)
-    mids, sums = tile_dedup(out_ids_ref[...], v)
+    mids, sums = tile_dedup(out_ids_ref[...], out_ids_row_ref[...], v)
     local = _iota_cols(bk, block_s, offset=si * block_s)
-    oh_out = (mids[:, None] == local).astype(jnp.float32)
+    oh_out = (mids == local).astype(jnp.float32)
     out_ref[...] += jax.lax.dot_general(
         oh_out, sums, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        precision=EXACT, preferred_element_type=jnp.float32)
 
 
 def _fused_pallas(view_plane, out_ids, vals, sources, spec, *, block_s: int,
@@ -206,25 +206,27 @@ def _fused_pallas(view_plane, out_ids, vals, sources, spec, *, block_s: int,
         plane_args.append(fpad(plane, _round_up(plane.shape[0], 8)))
         # gather-id pad rows index row 0; their value rows are ring-zero
         # and their out_ids are -1, so they contribute nothing
-        id_args.append(jnp.pad(ids.astype(jnp.int32), (0, Bp - B)))
+        id_args.append(_col(jnp.pad(ids.astype(jnp.int32), (0, Bp - B))))
     n_src = len(id_args)
     grid = (Sp // bs, Bp // bk)
+    out_ids = jnp.pad(out_ids.astype(jnp.int32), (0, Bp - B),
+                      constant_values=-1)
     in_specs = (
-        [pl.BlockSpec((bk,), lambda s, k: (k,)),
+        [_col_spec(bk), _row_spec(bk),
          pl.BlockSpec((bk, dp), lambda s, k: (k, 0))]
-        + [pl.BlockSpec((bk,), lambda s, k: (k,)) for _ in range(n_src)]
+        + [_col_spec(bk) for _ in range(n_src)]
         + [pl.BlockSpec((p.shape[0], dp), lambda s, k: (0, 0))
            for p in plane_args]
         + [pl.BlockSpec((bs, dp), lambda s, k: (s, 0))])
-    out = pl.pallas_call(
+    out = per_device(pl.pallas_call(
         functools.partial(_fused_kernel, block_s=bs, n_src=n_src, spec=spec),
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bs, dp), lambda s, k: (s, 0)),
         out_shape=jax.ShapeDtypeStruct((Sp, dp), jnp.float32),
         interpret=interpret,
-    )(jnp.pad(out_ids.astype(jnp.int32), (0, Bp - B), constant_values=-1),
-      fpad(vals, Bp), *id_args, *plane_args, fpad(view_plane, Sp))
+    ))(_col(out_ids), _row(out_ids), fpad(vals, Bp), *id_args, *plane_args,
+       fpad(view_plane, Sp))
     return out[:S, :d]
 
 

@@ -3,14 +3,14 @@
 F-IVM's trigger cost is dominated by ⊎ — scatter-adding a delta batch into
 a materialized view — and the sibling gathers that feed it.  XLA lowers a
 generic scatter to a per-row serialized loop on CPU/TPU; the TPU-native
-formulation is the same one-hot matmul used by ``segment_ring_sum``, here
-generalized to *accumulate into an existing view* so the whole ⊎ is one
-kernel:
+formulation is a one-hot matmul that *accumulates into an existing view*,
+so the whole ⊎ is one kernel:
 
   ``scatter_add_onehot``     out = view + 1h(ids)ᵀ · values
+  ``segment_ring_sum``       out = 1h(ids)ᵀ · values  (the same, into zeros)
   ``gather_mul_scatter``     out = view + 1h(out_ids)ᵀ · (scale ⊙ 1h(in_ids) · src)
 
-Both build their one-hot blocks on the fly in VMEM (the one-hot matrix
+All build their one-hot blocks on the fly in VMEM (the one-hot matrix
 never exists in HBM) and run the contraction on the MXU.  Grid =
 (S/bs, d/bd, B/bk) with the batch innermost: the revisited output block is
 initialized from the view block once (k == 0) and accumulated into across
@@ -28,6 +28,13 @@ this kernel when the source segment space fits VMEM.
 Key linearization (multi-column COO keys -> flat segment ids), payload
 pytree flattening, padding to block multiples, and backend choice all live
 in ``scatter_ops.py`` — these kernels see only ``[S, d]`` f32 planes.
+
+Per-row operands (ids, scales) enter as ``[B, 1]`` columns blocked
+``(block_k, 1)``, and the in-tile dedup also takes the ids as a ``[1, B]``
+row.  Mosaic refuses 1-D int32 blocks smaller than the array: XLA tiles a
+1-D array of 1,024 or more elements ``T(1024)``, while the kernel operand
+asks for ``T(block_k)``, so every batch that spans more than one id block
+would fail to compile on the TPU.
 """
 from __future__ import annotations
 
@@ -36,6 +43,25 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.sharding import PartitionSpec
+
+#: contraction precision: f32 on the MXU (``repro.core.rings.EXACT``)
+EXACT = jax.lax.Precision.HIGHEST
+
+
+def per_device(call):
+    """``call`` (a Pallas kernel) run whole on every device of the ambient
+    mesh.  JAX cannot partition a Mosaic kernel: in a program over several
+    devices it refuses one outside ``shard_map``.  The sharded stream
+    executor traces under its mesh (``jax.set_mesh``), so there each kernel
+    takes replicated operands -- GSPMD all-gathers a sharded view first --
+    and returns the same replicated result on every device.  Without a
+    multi-device mesh the kernel is called directly."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.size == 1:
+        return call
+    return jax.shard_map(call, mesh=mesh, in_specs=PartitionSpec(),
+                         out_specs=PartitionSpec(), check_vma=False)
 
 
 def _iota_cols(rows: int, cols: int, offset=0):
@@ -43,6 +69,27 @@ def _iota_cols(rows: int, cols: int, offset=0):
     no 1-D iota)."""
     it = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
     return it + offset
+
+
+def _col(x):
+    """[B] -> [B, 1]: the kernels' per-row operand layout."""
+    return x.reshape(-1, 1)
+
+
+def _row(x):
+    """[B] -> [1, B]: the transposed id layout the in-tile dedup reads."""
+    return x.reshape(1, -1)
+
+
+def _col_spec(block_k: int):
+    """(block_k, 1) blocks of a [B, 1] column, indexed by the last grid
+    axis (the batch, innermost in every kernel here)."""
+    return pl.BlockSpec((block_k, 1), lambda *g: (g[-1], 0))
+
+
+def _row_spec(block_k: int):
+    """(1, block_k) blocks of a [1, B] row, indexed like ``_col_spec``."""
+    return pl.BlockSpec((1, block_k), lambda *g: (0, g[-1]))
 
 
 def _scatter_kernel(ids_ref, vals_ref, view_ref, out_ref, *, block_s: int):
@@ -53,43 +100,46 @@ def _scatter_kernel(ids_ref, vals_ref, view_ref, out_ref, *, block_s: int):
     def _init():
         out_ref[...] = view_ref[...].astype(jnp.float32)
 
-    ids = ids_ref[...]  # [bk] int32
+    ids = ids_ref[...]  # [bk, 1] int32
     vals = vals_ref[...].astype(jnp.float32)  # [bk, bd]
     local = _iota_cols(ids.shape[0], block_s, offset=si * block_s)
-    onehot = (ids[:, None] == local).astype(jnp.float32)  # [bk, bs]
+    onehot = (ids == local).astype(jnp.float32)  # [bk, bs]
     out_ref[...] += jax.lax.dot_general(
-        onehot, vals, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
+        onehot, vals, (((0,), (0,)), ((), ())), precision=EXACT,
+        preferred_element_type=jnp.float32)
 
 
-def tile_dedup(ids, vals):
+def tile_dedup(ids, ids_row, vals):
     """Per-tile key dedup, entirely in VMEM: collapse duplicate ids within
-    one batch tile onto their first occurrence.
+    one batch tile onto their first occurrence.  ``ids`` is the tile's
+    ``[bk, 1]`` id column and ``ids_row`` the same ids as a ``[1, bk]``
+    row (the TPU has no cheap in-register transpose of an id column).
 
     Returns ``(mids, sums)`` where ``sums[i] = Σ_j [ids[j] == ids[i]] ·
-    vals[j]`` for the first occurrence of each id and ``mids`` masks every
-    later duplicate (and padding, ids < 0) to ``-1``.  The duplicate-sum is
-    a 0/1 matmul, so integer-valued f32 payloads dedup exactly — this is
-    the in-kernel replacement for the global sort/rank compaction prepass
-    (``scatter_ops._compact_scatter``) on the fused plan path; the
-    standalone compact backends keep the global prepass, whose O(B log B)
-    sort amortizes when one dedup serves the whole batch."""
+    vals[j]`` for the first occurrence of each id and ``mids`` ``[bk, 1]``
+    masks every later duplicate (and padding, ids < 0) to ``-1``.  The
+    duplicate-sum is a 0/1 matmul, so integer-valued f32 payloads dedup
+    exactly — this is the in-kernel replacement for the global sort/rank
+    compaction prepass (``scatter_ops._compact_scatter``) on the fused
+    plan path; the standalone compact backends keep the global prepass,
+    whose O(B log B) sort amortizes when one dedup serves the whole
+    batch."""
     bk = ids.shape[0]
     row = jax.lax.broadcasted_iota(jnp.int32, (bk, bk), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (bk, bk), 1)
-    eq = ids[:, None] == ids[None, :]
+    eq = ids == ids_row
     # row i is its id's tile-first occurrence iff no earlier row matches
-    first = ~jnp.any(eq & (col < row), axis=1)  # [bk]
-    gather = (eq & first[:, None]).astype(jnp.float32)
+    first = ~jnp.any(eq & (col < row), axis=1, keepdims=True)  # [bk, 1]
+    gather = (eq & first).astype(jnp.float32)
     sums = jax.lax.dot_general(
         gather, vals, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        precision=EXACT, preferred_element_type=jnp.float32)
     mids = jnp.where(first & (ids >= 0), ids, -1)
     return mids, sums
 
 
-def _scatter_dedup_kernel(ids_ref, vals_ref, view_ref, out_ref, *,
-                          block_s: int):
+def _scatter_dedup_kernel(ids_ref, ids_row_ref, vals_ref, view_ref, out_ref,
+                          *, block_s: int):
     si = pl.program_id(0)
     k = pl.program_id(2)
 
@@ -97,12 +147,13 @@ def _scatter_dedup_kernel(ids_ref, vals_ref, view_ref, out_ref, *,
     def _init():
         out_ref[...] = view_ref[...].astype(jnp.float32)
 
-    mids, sums = tile_dedup(ids_ref[...], vals_ref[...].astype(jnp.float32))
+    mids, sums = tile_dedup(ids_ref[...], ids_row_ref[...],
+                            vals_ref[...].astype(jnp.float32))
     local = _iota_cols(mids.shape[0], block_s, offset=si * block_s)
-    onehot = (mids[:, None] == local).astype(jnp.float32)  # [bk, bs]
+    onehot = (mids == local).astype(jnp.float32)  # [bk, bs]
     out_ref[...] += jax.lax.dot_general(
         onehot, sums, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        precision=EXACT, preferred_element_type=jnp.float32)
 
 
 def scatter_add_onehot(
@@ -125,19 +176,34 @@ def scatter_add_onehot(
     assert d2 == d, (values.shape, view.shape)
     assert B % block_k == 0 and d % block_d == 0 and S % block_s == 0
     grid = (S // block_s, d // block_d, B // block_k)
+    ids_args, ids_specs = [_col(seg_ids)], [_col_spec(block_k)]
+    if dedup:
+        ids_args.append(_row(seg_ids))
+        ids_specs.append(_row_spec(block_k))
     kernel = _scatter_dedup_kernel if dedup else _scatter_kernel
-    return pl.pallas_call(
+    return per_device(pl.pallas_call(
         functools.partial(kernel, block_s=block_s),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_k,), lambda s, j, k: (k,)),
+        in_specs=ids_specs + [
             pl.BlockSpec((block_k, block_d), lambda s, j, k: (k, j)),
             pl.BlockSpec((block_s, block_d), lambda s, j, k: (s, j)),
         ],
         out_specs=pl.BlockSpec((block_s, block_d), lambda s, j, k: (s, j)),
         out_shape=jax.ShapeDtypeStruct((S, d), jnp.float32),
         interpret=interpret,
-    )(seg_ids, values, view)
+    ))(*ids_args, values, view)
+
+
+def segment_ring_sum(values, seg_ids, num_segments: int, *,
+                     block_s: int = 128, block_d: int = 128,
+                     block_k: int = 512, interpret: bool = False):
+    """Group-by ⊕ of a COO batch: values [B, d] at seg_ids [B] -> [S, d]
+    f32, i.e. the one-hot scatter into a zero view.  B, d, S must be
+    multiples of the block sizes (callers pad)."""
+    zeros = jnp.zeros((num_segments, values.shape[1]), jnp.float32)
+    return scatter_add_onehot(zeros, seg_ids, values, block_s=block_s,
+                              block_d=block_d, block_k=block_k,
+                              interpret=interpret)
 
 
 def _gms_kernel(out_ids_ref, in_ids_ref, scale_ref, src_ref, view_ref, out_ref,
@@ -149,21 +215,21 @@ def _gms_kernel(out_ids_ref, in_ids_ref, scale_ref, src_ref, view_ref, out_ref,
     def _init():
         out_ref[...] = view_ref[...].astype(jnp.float32)
 
-    oid = out_ids_ref[...]  # [bk]
-    iid = in_ids_ref[...]  # [bk]
-    scale = scale_ref[...].astype(jnp.float32)  # [bk]
+    oid = out_ids_ref[...]  # [bk, 1]
+    iid = in_ids_ref[...]  # [bk, 1]
+    scale = scale_ref[...].astype(jnp.float32)  # [bk, 1]
     src = src_ref[...].astype(jnp.float32)  # [Sg, bd]
     bk = oid.shape[0]
     # gather = one-hot(in_ids) · src, built in VMEM, contracted on the MXU
-    oh_in = (iid[:, None] == _iota_cols(bk, num_src)).astype(jnp.float32)
+    oh_in = (iid == _iota_cols(bk, num_src)).astype(jnp.float32)
     gathered = jax.lax.dot_general(
-        oh_in, src, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )  # [bk, bd]
-    vals = gathered * scale[:, None]
-    oh_out = (oid[:, None] == _iota_cols(bk, block_s, offset=si * block_s))
+        oh_in, src, (((1,), (0,)), ((), ())), precision=EXACT,
+        preferred_element_type=jnp.float32)  # [bk, bd]
+    vals = gathered * scale
+    oh_out = (oid == _iota_cols(bk, block_s, offset=si * block_s))
     out_ref[...] += jax.lax.dot_general(
         oh_out.astype(jnp.float32), vals, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
+        precision=EXACT, preferred_element_type=jnp.float32,
     )
 
 
@@ -191,17 +257,17 @@ def gather_mul_scatter(
     assert d2 == d and in_ids.shape[0] == B and scale.shape[0] == B
     assert B % block_k == 0 and d % block_d == 0 and S % block_s == 0
     grid = (S // block_s, d // block_d, B // block_k)
-    return pl.pallas_call(
+    return per_device(pl.pallas_call(
         functools.partial(_gms_kernel, block_s=block_s, num_src=Sg),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_k,), lambda s, j, k: (k,)),
-            pl.BlockSpec((block_k,), lambda s, j, k: (k,)),
-            pl.BlockSpec((block_k,), lambda s, j, k: (k,)),
+            _col_spec(block_k),
+            _col_spec(block_k),
+            _col_spec(block_k),
             pl.BlockSpec((Sg, block_d), lambda s, j, k: (0, j)),
             pl.BlockSpec((block_s, block_d), lambda s, j, k: (s, j)),
         ],
         out_specs=pl.BlockSpec((block_s, block_d), lambda s, j, k: (s, j)),
         out_shape=jax.ShapeDtypeStruct((S, d), jnp.float32),
         interpret=interpret,
-    )(out_ids, in_ids, scale, src, view)
+    ))(_col(out_ids), _col(in_ids), _col(scale), src, view)

@@ -50,7 +50,7 @@ from repro.core.storage import (comp_width, flatten_payload, linear_ids,
 from . import ref
 from .ring_scatter import gather_mul_scatter as _gms_pallas
 from .ring_scatter import scatter_add_onehot as _scatter_pallas
-from .segment_ring_sum import segment_ring_sum as _segsum_pallas
+from .ring_scatter import segment_ring_sum as _segsum_pallas
 
 #: back-compat alias — the key-linearization / payload-plane shim is owned
 #: by the storage layer (repro.core.storage) since the ViewStorage redesign

@@ -286,23 +286,48 @@ ex = shard_executor(sharded)
 ex.run(stream)
 got = np.asarray(sharded.result().transpose(("A", "C")).payload["v"])
 ref = np.asarray(single.result().transpose(("A", "C")).payload["v"])
+from repro.core.plan import FusedChain
 print(json.dumps(dict(match=bool(np.array_equal(got, ref)),
                       sharded_views=list(ex.shard.sharded_views()),
+                      fused_chains=sum(isinstance(op, FusedChain)
+                                       for p in sharded.plans.plans.values()
+                                       for op in p.ops),
                       devices=len(jax.devices()))))
 """
 
+#: the same child with the Pallas kernels (interpret mode) and plan fusion
+#: forced on: the sharded program runs them per device
+#: (``ring_scatter.per_device``), as it must on TPU chips
+_KERNEL_CHILD = _CHILD.replace("""
+assert len(jax.devices()) == 4""", """
+from repro.core import plan as plan_mod
+from repro.kernels import scatter_ops
+scatter_ops.set_backend("onehot_interpret")
+plan_mod.set_fusion("on")
+assert len(jax.devices()) == 4""")
 
-def test_sharded_equivalence_forced_host_devices():
+
+def _run_child(code: str) -> dict:
     env = dict(os.environ)
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + " --xla_force_host_platform_device_count=4").strip()
     env["JAX_PLATFORMS"] = "cpu"
     src = os.path.join(os.path.dirname(__file__), "..", "src")
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-    out = subprocess.run([sys.executable, "-c", _CHILD], env=env,
+    out = subprocess.run([sys.executable, "-c", code], env=env,
                          capture_output=True, text=True, timeout=600)
     assert out.returncode == 0, out.stderr[-2000:]
     report = json.loads(out.stdout.strip().splitlines()[-1])
     assert report["devices"] == 4
     assert report["match"], report
     assert report["sharded_views"], "nothing sharded on a 4-device mesh"
+    return report
+
+
+def test_sharded_equivalence_forced_host_devices():
+    _run_child(_CHILD)
+
+
+def test_sharded_kernels_per_device_forced_host_devices():
+    report = _run_child(_KERNEL_CHILD)
+    assert report["fused_chains"], "no fused chain reached the kernels"
