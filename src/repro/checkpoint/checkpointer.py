@@ -41,14 +41,13 @@ import logging
 import os
 import shutil
 import threading
-import time
 import zlib
 from typing import Any
 
 import jax
 import numpy as np
 
-from repro.runtime import faults
+from repro.runtime import faults, tracing
 
 log = logging.getLogger("repro.checkpoint")
 
@@ -78,10 +77,9 @@ class Checkpointer:
         os.makedirs(directory, exist_ok=True)
         self._thread: threading.Thread | None = None
         self._error: BaseException | None = None
-        #: wall seconds of the last completed ``_write`` (device→host
-        #: transfer included when ``sync_copy=False``) and the cumulative
-        #: total — the BENCH_stream checkpointing-leg telemetry
-        self.last_write_seconds: float = 0.0
+        #: wall seconds of the completed ``_write``s (device→host
+        #: transfer included when ``sync_copy=False``) — the BENCH_stream
+        #: checkpointing-leg telemetry
         self.total_write_seconds: float = 0.0
         self.saves_committed: int = 0
         #: steps quarantined (renamed ``corrupt_step_*``) this process —
@@ -142,48 +140,47 @@ class Checkpointer:
 
     def _write(self, leaves, treedef_str: str, step: int,
                meta: dict | None = None) -> None:
-        t0 = time.perf_counter()
-        # device -> host copy (no-op for host arrays): on the writer
-        # thread this is where an async save blocks on in-flight device
-        # computation instead of the caller doing so
-        host_leaves = [np.asarray(x) for x in leaves]
-        final = os.path.join(self.directory, f"step_{step:08d}")
-        tmp = final + ".tmp"
-        if os.path.exists(tmp):
-            shutil.rmtree(tmp)
-        os.makedirs(tmp)
-        manifest = {
-            "step": step,
-            "n_leaves": len(host_leaves),
-            "treedef": treedef_str,
-            # per-leaf content fingerprint: restore re-hashes each leaf
-            # file and refuses a snapshot whose bytes changed after
-            # commit — the atomic rename protects against torn writes,
-            # the crc32 against silent post-commit corruption
-            "leaves": [{"shape": list(x.shape), "dtype": str(x.dtype),
-                        "crc32": zlib.crc32(np.ascontiguousarray(x)
-                                            .tobytes()) & 0xFFFFFFFF}
-                       for x in host_leaves],
-            "meta": meta or {},
-        }
-        for i, x in enumerate(host_leaves):
-            np.save(os.path.join(tmp, f"leaf_{i}.npy"), x)
-        with open(os.path.join(tmp, "manifest.json"), "w") as f:
-            json.dump(manifest, f)
-            f.flush()
-            os.fsync(f.fileno())
-        # a kill between here and the rename must leave the newest
-        # *committed* step untouched (the chaos suite injects exactly this)
-        faults.crossing("mid_checkpoint_write", step=step)
-        if os.path.exists(final):
-            shutil.rmtree(final)
-        os.rename(tmp, final)  # atomic commit
-        # bit-flip fault point: the snapshot is durable and GC-visible —
-        # a "bitflip" plan corrupts it here, post-commit
-        faults.crossing("snapshot_committed", step=step,
-                        path=os.path.join(final, "leaf_0.npy"))
-        self.last_write_seconds = time.perf_counter() - t0
-        self.total_write_seconds += self.last_write_seconds
+        with tracing.span("fivm.checkpoint.write") as write:
+            # device -> host copy (no-op for host arrays): on the writer
+            # thread this is where an async save blocks on in-flight
+            # device computation instead of the caller doing so
+            host_leaves = [np.asarray(x) for x in leaves]
+            final = os.path.join(self.directory, f"step_{step:08d}")
+            tmp = final + ".tmp"
+            if os.path.exists(tmp):
+                shutil.rmtree(tmp)
+            os.makedirs(tmp)
+            manifest = {
+                "step": step,
+                "n_leaves": len(host_leaves),
+                "treedef": treedef_str,
+                # per-leaf content fingerprint: restore re-hashes each leaf
+                # file and refuses a snapshot whose bytes changed after
+                # commit — the atomic rename protects against torn writes,
+                # the crc32 against silent post-commit corruption
+                "leaves": [{"shape": list(x.shape), "dtype": str(x.dtype),
+                            "crc32": zlib.crc32(np.ascontiguousarray(x)
+                                                .tobytes()) & 0xFFFFFFFF}
+                           for x in host_leaves],
+                "meta": meta or {},
+            }
+            for i, x in enumerate(host_leaves):
+                np.save(os.path.join(tmp, f"leaf_{i}.npy"), x)
+            with open(os.path.join(tmp, "manifest.json"), "w") as f:
+                json.dump(manifest, f)
+                f.flush()
+                os.fsync(f.fileno())
+            # a kill between here and the rename must leave the newest
+            # *committed* step untouched (the chaos suite injects this)
+            faults.crossing("mid_checkpoint_write", step=step)
+            if os.path.exists(final):
+                shutil.rmtree(final)
+            os.rename(tmp, final)  # atomic commit
+            # bit-flip fault point: the snapshot is durable and
+            # GC-visible — a "bitflip" plan corrupts it here, post-commit
+            faults.crossing("snapshot_committed", step=step,
+                            path=os.path.join(final, "leaf_0.npy"))
+        self.total_write_seconds += write.wall
         self.saves_committed += 1
         self._gc()
 

@@ -65,10 +65,6 @@ class StreamCheckpointer:
         if segment_updates is not None and segment_updates < 1:
             raise ValueError("segment_updates must be >= 1")
         self.segment_updates = segment_updates
-        #: host seconds spent *dispatching* the last boundary save (the
-        #: stall the executor's pipeline actually pays; the write itself
-        #: runs on the writer thread — see ``write_seconds``)
-        self.last_dispatch_seconds: float = 0.0
 
     # ------------------------------------------------------------------ save
     def save_boundary(self, engine, offset: int, segment: int,
@@ -87,9 +83,6 @@ class StreamCheckpointer:
         twice: the executor passes the registry's stamped copies here
         and only the remaining leaves (unserved views, base relations,
         indicators) are copied fresh."""
-        import time
-
-        t0 = time.perf_counter()
         state = engine.canonical_state()
         meta = {
             "offset": int(offset),
@@ -115,7 +108,6 @@ class StreamCheckpointer:
                 copies = jax.tree.map(jnp.copy, state)
             self.ckpt.save(copies, step=int(offset), blocking=False,
                            meta=meta, sync_copy=False)
-        self.last_dispatch_seconds = time.perf_counter() - t0
 
     def wait(self) -> None:
         """Block until the pending boundary save committed (re-raising a

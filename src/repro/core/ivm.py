@@ -29,6 +29,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.runtime import tracing
+
 from . import plan as plan_mod
 from . import storage as storage_mod
 from .indicators import IndicatorState, add_indicators
@@ -89,79 +91,80 @@ class IVMEngine:
         (repro.runtime.integrity): views can only be recomputed from
         base relations that are actually kept.  Default (``None``)
         derives it from the strategy as before."""
-        updatable = tuple(updatable if updatable is not None else query.relations)
-        vo = var_order or heuristic_order(query)
-        tree = build_view_tree(query, vo, fuse_chains=fuse_chains)
-        if use_indicators:
-            assert strategy in ("fivm", "dbt", "reeval"), (
-                "1-IVM has no intermediate views; indicator projections do not apply"
+        with tracing.span("fivm.build"):
+            updatable = tuple(updatable if updatable is not None else query.relations)
+            vo = var_order or heuristic_order(query)
+            tree = build_view_tree(query, vo, fuse_chains=fuse_chains)
+            if use_indicators:
+                assert strategy in ("fivm", "dbt", "reeval"), (
+                    "1-IVM has no intermediate views; indicator projections do not apply"
+                )
+                tree = add_indicators(tree, query)
+
+            if strategy == "fivm":
+                mat = choose_materialized(tree, updatable)
+            elif strategy == "dbt":
+                mat = {n.name for n in tree.walk()}
+            elif strategy in ("fivm_1", "reeval"):
+                mat = {tree.name} | {n.name for n in tree.walk() if n.is_leaf}
+            else:  # pragma: no cover
+                raise ValueError(strategy)
+
+            store_base = strategy in ("fivm_1", "reeval") or bool(store_base)
+            # indicator-bearing nodes need their base relation stored and all
+            # children materialized when the indicator's relation is updatable
+            indicators: dict[str, IndicatorState] = {}
+            for n in tree.walk():
+                if n.indicator is not None:
+                    r, proj = n.indicator
+                    indicators[n.name] = IndicatorState.init(r, database[r], proj, query)
+                    if r in updatable:
+                        mat |= {c.name for c in n.children}
+                        mat |= {ln.name for ln in tree.walk() if ln.is_leaf and ln.relation == r}
+
+            views: dict[str, DenseRelation] = {}
+            store: dict[str, DenseRelation] = {}
+            evaluate_view(tree, database, query, store=store, premarg=premarg)
+            if premarg:
+                # the factorized result representation: every pre-marginalization
+                # view is part of the maintained output (Sec. 7.3)
+                mat |= {k for k in store if k.startswith("W:")}
+            for name in mat:
+                views[name] = store[name]
+            # storage planning: convert each materialized view to its planned
+            # backend (dense small views, hashed-COO sparse large/low-fill ones)
+            plan = storage_mod.plan_storage(
+                views, tree=tree, updatable=updatable, strategy=strategy,
+                mode=storage, overrides=storage_overrides,
+                **dict(storage_opts or {}))
+            views = storage_mod.apply_storage_plan(views, plan)
+            # base relations are stored (as copies: leaf views alias the caller's
+            # database arrays, and state donation requires every buffer in the
+            # state pytree to appear exactly once) only where maintenance reads
+            # them back: 1-IVM / reevaluation recompute from base, and indicator
+            # transition counting needs the pre-update relation.  fivm / dbt
+            # never read other base relations — storing them would just add a
+            # dead scatter per update and inflate the stream executor's carry.
+            need_base = set(query.relations) if store_base else {
+                n.indicator[0] for n in tree.walk() if n.indicator is not None
+            }
+            base = {
+                r: DenseRelation(rel.schema, rel.ring,
+                                 {c: jnp.array(v) for c, v in rel.payload.items()})
+                for r, rel in database.items() if r in need_base
+            }
+            return cls(
+                query=query,
+                tree=tree,
+                materialized_names=mat,
+                views=views,
+                base=base,
+                indicators=indicators,
+                strategy=strategy,
+                updatable=updatable,
+                store_base=store_base,
+                storage_plan=plan,
             )
-            tree = add_indicators(tree, query)
-
-        if strategy == "fivm":
-            mat = choose_materialized(tree, updatable)
-        elif strategy == "dbt":
-            mat = {n.name for n in tree.walk()}
-        elif strategy in ("fivm_1", "reeval"):
-            mat = {tree.name} | {n.name for n in tree.walk() if n.is_leaf}
-        else:  # pragma: no cover
-            raise ValueError(strategy)
-
-        store_base = strategy in ("fivm_1", "reeval") or bool(store_base)
-        # indicator-bearing nodes need their base relation stored and all
-        # children materialized when the indicator's relation is updatable
-        indicators: dict[str, IndicatorState] = {}
-        for n in tree.walk():
-            if n.indicator is not None:
-                r, proj = n.indicator
-                indicators[n.name] = IndicatorState.init(r, database[r], proj, query)
-                if r in updatable:
-                    mat |= {c.name for c in n.children}
-                    mat |= {ln.name for ln in tree.walk() if ln.is_leaf and ln.relation == r}
-
-        views: dict[str, DenseRelation] = {}
-        store: dict[str, DenseRelation] = {}
-        evaluate_view(tree, database, query, store=store, premarg=premarg)
-        if premarg:
-            # the factorized result representation: every pre-marginalization
-            # view is part of the maintained output (Sec. 7.3)
-            mat |= {k for k in store if k.startswith("W:")}
-        for name in mat:
-            views[name] = store[name]
-        # storage planning: convert each materialized view to its planned
-        # backend (dense small views, hashed-COO sparse large/low-fill ones)
-        plan = storage_mod.plan_storage(
-            views, tree=tree, updatable=updatable, strategy=strategy,
-            mode=storage, overrides=storage_overrides,
-            **dict(storage_opts or {}))
-        views = storage_mod.apply_storage_plan(views, plan)
-        # base relations are stored (as copies: leaf views alias the caller's
-        # database arrays, and state donation requires every buffer in the
-        # state pytree to appear exactly once) only where maintenance reads
-        # them back: 1-IVM / reevaluation recompute from base, and indicator
-        # transition counting needs the pre-update relation.  fivm / dbt
-        # never read other base relations — storing them would just add a
-        # dead scatter per update and inflate the stream executor's carry.
-        need_base = set(query.relations) if store_base else {
-            n.indicator[0] for n in tree.walk() if n.indicator is not None
-        }
-        base = {
-            r: DenseRelation(rel.schema, rel.ring,
-                             {c: jnp.array(v) for c, v in rel.payload.items()})
-            for r, rel in database.items() if r in need_base
-        }
-        return cls(
-            query=query,
-            tree=tree,
-            materialized_names=mat,
-            views=views,
-            base=base,
-            indicators=indicators,
-            strategy=strategy,
-            updatable=updatable,
-            store_base=store_base,
-            storage_plan=plan,
-        )
 
     # ---------------------------------------------------------------- result
     def result(self) -> DenseRelation:

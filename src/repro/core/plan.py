@@ -1205,7 +1205,8 @@ def run_coo_ops(ops, views: Mapping, query: Query, upd: COOUpdate,
         elif isinstance(op, Gather):
             view = _resolve_view(op.view, views, ind_dense)
             plane = memo.get(("plane", op.view)) if memo else None
-            delta = delta.join_dense(view, src_plane=plane)
+            with jax.named_scope("fivm.gather"):
+                delta = delta.join_dense(view, src_plane=plane)
         elif isinstance(op, JoinContract):
             view = _resolve_view(op.view, views, ind_dense)
             if op.densifies and memo:
@@ -1219,11 +1220,13 @@ def run_coo_ops(ops, views: Mapping, query: Query, upd: COOUpdate,
         elif isinstance(op, Emit):
             deltas[op.view] = delta
         elif isinstance(op, ScatterAccum):
-            updated[op.view] = delta.apply_to(views[op.view],
-                                              backend=op.backend)
+            with jax.named_scope("fivm.scatter"):
+                updated[op.view] = delta.apply_to(views[op.view],
+                                                  backend=op.backend)
         elif isinstance(op, FusedChain):
-            delta = _run_fused_chain(op, delta, views, query, ind_dense,
-                                     memo, deltas, updated)
+            with jax.named_scope("fivm.fused_chain"):
+                delta = _run_fused_chain(op, delta, views, query, ind_dense,
+                                         memo, deltas, updated)
         else:  # pragma: no cover
             raise TypeError(op)
     return PropagationResult(deltas, updated)
@@ -1540,10 +1543,12 @@ def execute_trigger(engine, plan: TriggerPlan, views, base, indicators,
         res = run_coo_ops(plan.ops, views, query, upd, ind_dense, memo=memo)
     views.update(res.updated)
     if plan.write_base:
-        base[plan.rel] = engine._bump_base(base[plan.rel], upd)
+        with jax.named_scope("fivm.base_bump"):
+            base[plan.rel] = engine._bump_base(base[plan.rel], upd)
     if plan.ind_ops:
-        run_indicator_ops(plan.ind_ops, views, indicators, query, upd,
-                          old_base)
+        with jax.named_scope("fivm.indicator"):
+            run_indicator_ops(plan.ind_ops, views, indicators, query, upd,
+                              old_base)
     return views, base, indicators
 
 

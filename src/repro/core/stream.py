@@ -81,14 +81,13 @@ the plan's collectives at cross-shard read sites.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Any, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.runtime import faults
+from repro.runtime import faults, tracing
 from repro.runtime.fault_tolerance import StragglerMonitor
 
 from . import plan as plan_mod
@@ -388,16 +387,22 @@ def prepare_stream(
         # updates ride along per position (sharing the position's bucket)
         # and the compiled program applies them once after the rounds scan.
         pattern = tuple(sched[:period])
-        cols = [[u for (r, u) in stream[j::period]] for j in range(period)]
-        n_full = len(stream) // period
-        tail_len = len(stream) % period
-        buckets = tuple(max(u.batch for u in col) for col in cols)
-        xs = tuple(stack(col[:n_full], b) for col, b in zip(cols, buckets))
-        tail_upds = [cols[j][n_full].pad_to(ring, buckets[j])
-                     for j in range(tail_len)]
-        tail = tuple((u.keys, u.payload) for u in tail_upds)
-        if period == 1:
-            xs = xs[0]
+        with tracing.span("fivm.admit.stack"):
+            cols = [[u for (r, u) in stream[j::period]]
+                    for j in range(period)]
+            n_full = len(stream) // period
+            tail_len = len(stream) % period
+            buckets = tuple(max(u.batch for u in col) for col in cols)
+            xs = tuple(stack(col[:n_full], b)
+                       for col, b in zip(cols, buckets))
+            tail_upds = [cols[j][n_full].pad_to(ring, buckets[j])
+                         for j in range(tail_len)]
+            tail = tuple((u.keys, u.payload) for u in tail_upds)
+            if period == 1:
+                xs = xs[0]
+        with tracing.span("fivm.admit.plans"):
+            plans = verified(tuple(plan_for(r, b)
+                                   for r, b in zip(pattern, buckets)))
         return PreparedStream(
             mode="scan" if period == 1 else "rounds",
             rel_order=rel_order,
@@ -409,8 +414,7 @@ def prepare_stream(
             n_tuples=n_tuples,
             tail=tail,
             tail_len=tail_len,
-            plans=verified(tuple(plan_for(r, b)
-                                 for r, b in zip(pattern, buckets))),
+            plans=plans,
             storage_sig=storage_sig,
             backend_sig=backend_sig,
             fusion_sig=fusion_sig,
@@ -419,15 +423,18 @@ def prepare_stream(
     # aperiodic: uniform bucket + key width, switch over the schedule
     bucket = max(upd.batch for _, upd in stream)
     k_max = max(len(schemas[r]) for r in rel_order)
-    padded = [u.pad_to(ring, bucket) for _, u in stream]
-    keys = jnp.stack([
-        jnp.pad(u.keys, ((0, 0), (0, k_max - u.keys.shape[1])))
-        for u in padded
-    ])  # [T, B, k_max]
-    payload = {c: jnp.stack([u.payload[c] for u in padded])
-               for c in comp_names}
-    sched_ids = jnp.asarray(np.array([rel_order.index(r) for r in sched],
-                                     np.int32))
+    with tracing.span("fivm.admit.stack"):
+        padded = [u.pad_to(ring, bucket) for _, u in stream]
+        keys = jnp.stack([
+            jnp.pad(u.keys, ((0, 0), (0, k_max - u.keys.shape[1])))
+            for u in padded
+        ])  # [T, B, k_max]
+        payload = {c: jnp.stack([u.payload[c] for u in padded])
+                   for c in comp_names}
+        sched_ids = jnp.asarray(np.array([rel_order.index(r) for r in sched],
+                                         np.int32))
+    with tracing.span("fivm.admit.plans"):
+        plans = verified(tuple(plan_for(r, bucket) for r in rel_order))
     return PreparedStream(
         mode="switch",
         rel_order=rel_order,
@@ -437,7 +444,7 @@ def prepare_stream(
         n_steps=len(stream),
         buckets=(bucket,),
         n_tuples=n_tuples,
-        plans=verified(tuple(plan_for(r, bucket) for r in rel_order)),
+        plans=plans,
         storage_sig=storage_sig,
         backend_sig=backend_sig,
         fusion_sig=fusion_sig,
@@ -549,8 +556,10 @@ class StreamExecutor:
 
             def step(state, x):
                 cols = (x,) if prepared.mode == "scan" else x
-                memo = (plan_mod.build_prep_memo(shared, state[0])
-                        if shared else None)
+                memo = None
+                if shared:
+                    with jax.named_scope("fivm.gather"):
+                        memo = plan_mod.build_prep_memo(shared, state[0])
                 for rel, body, (keys, payload) in zip(pattern, bodies, cols):
                     state = body(state,
                                  COOUpdate(schema_of[rel], keys, payload),
@@ -756,6 +765,9 @@ class StreamExecutor:
         if not donate_input:
             state = jax.tree.map(
                 lambda x: x.copy() if hasattr(x, "copy") else x, state)
+            tracing.count("copy_bytes", sum(
+                x.nbytes for x in jax.tree.leaves(state)
+                if hasattr(x, "nbytes")))
         xs, tail = prepared.xs, prepared.tail
         if self.shard is not None:
             state = self.shard.place(state)
@@ -803,22 +815,39 @@ class StreamExecutor:
         where ``admitted_sub`` is the (possibly sanitized, possibly
         shortened) update list this segment will actually apply and
         ``deferred`` is the emergency-split remainder (``[(sub, grow),
-        ...]``) the segmented runner must splice after this segment."""
+        ...]``) the segmented runner must splice after this segment.
+        ``admit_seconds`` is the wall of the ``fivm.admit`` span; its
+        parts are spans too (``fivm.admit.integrity``, ``.rehash``,
+        ``.stack``, ``.plans``, ``.program``)."""
+        with tracing.span("fivm.admit") as admit:
+            faults.crossing("mid_admit", updates=len(sub_stream))
+            sub_stream, deferred = self._admit_updates(sub_stream, grow_caps,
+                                                       offset)
+            prepared = prepare_stream(self.engine, sub_stream,
+                                      check_capacity=False)
+            with tracing.span("fivm.admit.program"):
+                self.compiled(prepared)
+        return prepared, admit.wall, sub_stream, deferred
+
+    def _admit_updates(self, sub_stream, grow_caps, offset: int):
+        """Validated admission, the pre-segment rehash and the live
+        capacity re-audit of :meth:`_admit_segment`; returns the admitted
+        updates and the deferred remainder."""
         engine = self.engine
         cfg = self.integrity
-        t0 = time.perf_counter()
-        faults.crossing("mid_admit", updates=len(sub_stream))
         if cfg is not None and cfg.policy != "permissive":
             from repro.runtime import integrity as integrity_mod
 
-            sub_stream = integrity_mod.admit_stream(engine, sub_stream, cfg,
-                                                    base_offset=offset)
+            with tracing.span("fivm.admit.integrity"):
+                sub_stream = integrity_mod.admit_stream(
+                    engine, sub_stream, cfg, base_offset=offset)
         if grow_caps:
-            engine.views = {
-                name: (v.rehash(grow_caps[name]) if name in grow_caps
-                       else v)
-                for name, v in engine.views.items()
-            }
+            with tracing.span("fivm.admit.rehash"):
+                engine.views = {
+                    name: (v.rehash(grow_caps[name]) if name in grow_caps
+                           else v)
+                    for name, v in engine.views.items()
+                }
             # tables carry the grown capacities now, but nothing compiled
             # (or checkpointed) against them yet — the torn state the
             # post-rehash recovery path must survive
@@ -826,27 +855,26 @@ class StreamExecutor:
                             grown=sorted(grow_caps))
         deferred: list = []
         if cfg is not None and cfg.active and cfg.capacity_degrade:
-            try:
-                check_stream_capacity(engine, sub_stream)
-            except StreamCapacityError as e:
-                resegmented = capacity_segments(engine, sub_stream)
-                sub_stream, extra_grow = resegmented[0]
-                deferred = resegmented[1:]
-                if extra_grow:
-                    engine.views = {
-                        name: (v.rehash(extra_grow[name])
-                               if name in extra_grow else v)
-                        for name, v in engine.views.items()
-                    }
-                cfg.degrade_log.append(dict(
-                    kind="emergency_resegment",
-                    segments=1 + len(deferred),
-                    grow={k: int(v) for k, v in extra_grow.items()},
-                    occupancy=storage_mod.occupancy_report(engine.views),
-                    error=str(e)))
-        prepared = prepare_stream(engine, sub_stream, check_capacity=False)
-        self.compiled(prepared)
-        return prepared, time.perf_counter() - t0, sub_stream, deferred
+            with tracing.span("fivm.admit.integrity"):
+                try:
+                    check_stream_capacity(engine, sub_stream)
+                except StreamCapacityError as e:
+                    resegmented = capacity_segments(engine, sub_stream)
+                    sub_stream, extra_grow = resegmented[0]
+                    deferred = resegmented[1:]
+                    if extra_grow:
+                        engine.views = {
+                            name: (v.rehash(extra_grow[name])
+                                   if name in extra_grow else v)
+                            for name, v in engine.views.items()
+                        }
+                    cfg.degrade_log.append(dict(
+                        kind="emergency_resegment",
+                        segments=1 + len(deferred),
+                        grow={k: int(v) for k, v in extra_grow.items()},
+                        occupancy=storage_mod.occupancy_report(engine.views),
+                        error=str(e)))
+        return sub_stream, deferred
 
     def _eager_spill(self, stream, state, update_engine: bool, error):
         """Graceful degradation of an explicit-state run that failed its
@@ -858,27 +886,27 @@ class StreamExecutor:
         from repro.runtime import integrity as integrity_mod
 
         cfg = self.integrity
-        t0 = time.perf_counter()
-        stream = integrity_mod.admit_stream(self.engine, stream, cfg,
-                                            base_offset=0)
         engine = self.engine
-        views, base, indicators = (dict(state[0]), dict(state[1]),
-                                   dict(state[2]))
-        for rel, upd in stream:
-            touched, _, _ = engine.plans.write_sets(engine, rel)
-            views = {
-                name: (storage_mod.grow_if_loaded(
-                           v, engine._insert_budget(v, rel, upd))
-                       if name in touched else v)
-                for name, v in views.items()
-            }
-            views, base, indicators = engine.functional_update(
-                views, base, indicators, rel, upd)
-        integrity_mod.flush_dead_letters(cfg)
-        new_state = canonical_state((views, base, indicators))
+        with tracing.span("fivm.spill") as spill:
+            stream = integrity_mod.admit_stream(engine, stream, cfg,
+                                                base_offset=0)
+            views, base, indicators = (dict(state[0]), dict(state[1]),
+                                       dict(state[2]))
+            for rel, upd in stream:
+                touched, _, _ = engine.plans.write_sets(engine, rel)
+                views = {
+                    name: (storage_mod.grow_if_loaded(
+                               v, engine._insert_budget(v, rel, upd))
+                           if name in touched else v)
+                    for name, v in views.items()
+                }
+                views, base, indicators = engine.functional_update(
+                    views, base, indicators, rel, upd)
+            integrity_mod.flush_dead_letters(cfg)
+            new_state = canonical_state((views, base, indicators))
         cfg.degrade_log.append(dict(
             kind="eager_spill", updates=len(stream), error=str(error),
-            wall_s=time.perf_counter() - t0))
+            wall_s=spill.wall))
         if update_engine:
             engine.set_state(new_state)
         return new_state
@@ -899,7 +927,10 @@ class StreamExecutor:
         ``pipeline=False`` blocks on each segment's result before
         admitting the next — the serialized baseline the BENCH_stream
         ``segmented_pipeline`` row compares against.  Per-segment
-        admit/dispatch host times land in ``last_segment_stats``.
+        admit/dispatch/audit/publish/save host times (the walls of their
+        ``fivm.*`` spans) and the segment's counters (``counts``, from
+        :func:`repro.runtime.tracing.count`) land in
+        ``last_segment_stats``.
 
         With a :attr:`checkpoint` attached, every segment boundary
         snapshots the engine: the save dispatches device copies of the
@@ -932,6 +963,7 @@ class StreamExecutor:
             cfg.pending_dead_letters.clear()
         offset = base_offset
         queue = list(segments)
+        tracing.take_counts()  # a segment's entry holds only its own counts
         prepared, admit_s, sub, deferred = self._admit_segment(
             *queue[0], offset=offset)
         if deferred:
@@ -939,17 +971,16 @@ class StreamExecutor:
         i = 0
         while i < len(queue):
             n_steps = prepared.n_steps
-            t0 = time.perf_counter()
-            # segment 0's input can alias caller-visible arrays (the
-            # original database, the update_engine=False snapshot) and
-            # must be copied; later segments run on exclusively
-            # engine-owned outputs of the previous segment — donate them
-            # instead of paying a full-state device copy per segment
-            state = self.run(prepared, update_engine=True,
-                             donate_input=i > 0)
-            if not pipeline:
-                jax.block_until_ready(state)
-            dispatch_s = time.perf_counter() - t0
+            with tracing.span("fivm.dispatch") as dispatch:
+                # segment 0's input can alias caller-visible arrays (the
+                # original database, the update_engine=False snapshot)
+                # and must be copied; later segments run on exclusively
+                # engine-owned outputs of the previous segment — donate
+                # them instead of paying a full-state device copy
+                state = self.run(prepared, update_engine=True,
+                                 donate_input=i > 0)
+                if not pipeline:
+                    jax.block_until_ready(state)
             offset += len(sub)
             faults.crossing("mid_segment", segment=i, offset=offset)
             audit_s = 0.0
@@ -957,15 +988,15 @@ class StreamExecutor:
             if cfg is not None and cfg.audit_due(i):
                 from repro.runtime import integrity as integrity_mod
 
-                t1 = time.perf_counter()
-                records = integrity_mod.audit_engine(self.engine, cfg,
-                                                     segment=i)
-                if any(r.repaired for r in records):
-                    # the repair replaced engine views; the boundary
-                    # snapshot (and the next segment) must see it
-                    state = self.engine.state
-                audit_meta = integrity_mod.publish_meta(records)
-                audit_s = time.perf_counter() - t1
+                with tracing.span("fivm.audit") as audit:
+                    records = integrity_mod.audit_engine(self.engine, cfg,
+                                                         segment=i)
+                    if any(r.repaired for r in records):
+                        # the repair replaced engine views; the boundary
+                        # snapshot (and the next segment) must see it
+                        state = self.engine.state
+                    audit_meta = integrity_mod.publish_meta(records)
+                audit_s = audit.wall
             publish_s = 0.0
             snap = None
             if self.registry is not None:
@@ -974,31 +1005,32 @@ class StreamExecutor:
                 # next segment's admission can dispatch the program that
                 # donates these buffers — jnp.copy dispatches without a
                 # host sync, exactly like the async checkpoint save
-                t1 = time.perf_counter()
                 snap = self.registry.publish(self.engine.views,
                                              offset=offset, segment=i,
                                              meta=audit_meta)
-                publish_s = time.perf_counter() - t1
+                publish_s = self.registry.last_publish_seconds
             save_s = 0.0
             if ck is not None:
-                t1 = time.perf_counter()
-                ck.save_boundary(self.engine, offset=offset, segment=i,
-                                 blocking=not pipeline,
-                                 view_copies=(snap.views if snap is not None
-                                              else None))
-                if i + 1 == len(queue):
-                    ck.wait()  # a finished run is durably checkpointed
-                save_s = time.perf_counter() - t1
-            straggler = self.stragglers.observe(i, admit_s + dispatch_s)
+                with tracing.span("fivm.checkpoint") as save:
+                    ck.save_boundary(self.engine, offset=offset, segment=i,
+                                     blocking=not pipeline,
+                                     view_copies=(snap.views
+                                                  if snap is not None
+                                                  else None))
+                    if i + 1 == len(queue):
+                        ck.wait()  # a finished run is durably checkpointed
+                save_s = save.wall
+            straggler = self.stragglers.observe(i, admit_s + dispatch.wall)
             stats.append(dict(segment=i, n_steps=n_steps,
-                              admit_s=admit_s, dispatch_s=dispatch_s,
+                              admit_s=admit_s, dispatch_s=dispatch.wall,
                               save_s=save_s, audit_s=audit_s,
                               publish_s=publish_s,
                               generation=(self.registry.generation
                                           if self.registry is not None
                                           else None),
                               straggler=straggler,
-                              straggler_baseline=self.stragglers.baseline))
+                              straggler_baseline=self.stragglers.baseline,
+                              counts=tracing.take_counts()))
             if i + 1 < len(queue):
                 prepared, admit_s, sub, deferred = self._admit_segment(
                     *queue[i + 1], offset=offset)

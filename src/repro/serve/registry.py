@@ -40,6 +40,8 @@ from typing import Any, Mapping, Sequence
 import jax
 import jax.numpy as jnp
 
+from repro.runtime import tracing
+
 
 @dataclasses.dataclass
 class Snapshot:
@@ -89,6 +91,7 @@ class SnapshotRegistry:
         #: newest published generation (-1 before the first publish)
         self.generation: int = -1
         self.publishes: int = 0
+        #: wall of the last ``fivm.publish`` span
         self.last_publish_seconds: float = 0.0
         #: publish→first-read latencies (seconds) of retired generations
         self._first_read_s: list[float] = []
@@ -104,21 +107,21 @@ class SnapshotRegistry:
         host sync; the copies are safe against the next segment's buffer
         donation.  Returns the new :class:`Snapshot`.
         """
-        t0 = time.perf_counter()
-        names = (self.view_names if self.view_names is not None
-                 else tuple(views))
-        copies = {n: jax.tree.map(jnp.copy, views[n]) for n in names}
-        with self._lock:
-            gen = self.generation + 1
-            snap = Snapshot(generation=gen, offset=int(offset),
-                            segment=int(segment), views=copies,
-                            published_at=time.perf_counter(),
-                            meta=dict(meta or {}))
-            self._snaps[gen] = snap
-            self.generation = gen
-            self.publishes += 1
-            self._evict_locked()
-        self.last_publish_seconds = time.perf_counter() - t0
+        with tracing.span("fivm.publish") as span:
+            names = (self.view_names if self.view_names is not None
+                     else tuple(views))
+            copies = {n: jax.tree.map(jnp.copy, views[n]) for n in names}
+            with self._lock:
+                gen = self.generation + 1
+                snap = Snapshot(generation=gen, offset=int(offset),
+                                segment=int(segment), views=copies,
+                                published_at=time.perf_counter(),
+                                meta=dict(meta or {}))
+                self._snaps[gen] = snap
+                self.generation = gen
+                self.publishes += 1
+                self._evict_locked()
+        self.last_publish_seconds = span.wall
         return snap
 
     def _evict_locked(self) -> None:

@@ -36,6 +36,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.storage import next_pow2
+from repro.runtime import tracing
 
 from . import lookup as lookup_mod
 from .registry import Snapshot, SnapshotRegistry
@@ -55,7 +56,8 @@ class ReadResult:
 
     def host(self):
         """Explicit device→host materialization (the only sync)."""
-        return jax.device_get(self.data)
+        with tracing.span("fivm.read.host"):
+            return jax.device_get(self.data)
 
 
 class PinnedGeneration:
@@ -133,7 +135,8 @@ class ViewServer:
     # ----------------------------------------------------------- snapshots
     def pin(self, generation: int | None = None) -> PinnedGeneration:
         """Pin a generation (default newest) for multi-query reads."""
-        return PinnedGeneration(self, self.registry.pin(generation))
+        with tracing.span("fivm.read.pin"):
+            return PinnedGeneration(self, self.registry.pin(generation))
 
     def _resolve(self, snapshot: Snapshot | None,
                  generation: int | None) -> Snapshot:
@@ -168,45 +171,49 @@ class ViewServer:
     def point(self, view: str, keys, *, generation: int | None = None,
               snapshot: Snapshot | None = None) -> ReadResult:
         """Batched point lookup; absent keys read ring zero."""
-        snap = self._resolve(snapshot, generation)
-        v = self._view(snap, view)
-        padded, b = self._pad_keys(keys)
-        out = lookup_mod.point(v, padded)
-        data = {c: arr[:b] for c, arr in out.items()}
-        return ReadResult(view, "point", snap.generation, data)
+        with tracing.span("fivm.read"):
+            snap = self._resolve(snapshot, generation)
+            v = self._view(snap, view)
+            padded, b = self._pad_keys(keys)
+            out = lookup_mod.point(v, padded)
+            data = {c: arr[:b] for c, arr in out.items()}
+            return ReadResult(view, "point", snap.generation, data)
 
     def range_sum(self, view: str, lo, hi, *,
                   generation: int | None = None,
                   snapshot: Snapshot | None = None) -> ReadResult:
         """⊕ over linearized key ids in [lo, hi)."""
-        snap = self._resolve(snapshot, generation)
-        v = self._view(snap, view)
-        data = lookup_mod.range_sum(v, jnp.int32(lo), jnp.int32(hi))
-        return ReadResult(view, "range_sum", snap.generation, data)
+        with tracing.span("fivm.read"):
+            snap = self._resolve(snapshot, generation)
+            v = self._view(snap, view)
+            data = lookup_mod.range_sum(v, jnp.int32(lo), jnp.int32(hi))
+            return ReadResult(view, "range_sum", snap.generation, data)
 
     def range_scan(self, view: str, lo, hi, k: int, *,
                    generation: int | None = None,
                    snapshot: Snapshot | None = None) -> ReadResult:
         """First ``k`` live keys in [lo, hi), ascending linearized order:
         data = dict(keys=[k, nk], payload={comp: [k, *shp]}, valid=[k])."""
-        snap = self._resolve(snapshot, generation)
-        v = self._view(snap, view)
-        keys, payload, valid = lookup_mod.range_scan(
-            v, jnp.int32(lo), jnp.int32(hi), int(k))
-        return ReadResult(view, "range_scan", snap.generation,
-                          dict(keys=keys, payload=payload, valid=valid))
+        with tracing.span("fivm.read"):
+            snap = self._resolve(snapshot, generation)
+            v = self._view(snap, view)
+            keys, payload, valid = lookup_mod.range_scan(
+                v, jnp.int32(lo), jnp.int32(hi), int(k))
+            return ReadResult(view, "range_scan", snap.generation,
+                              dict(keys=keys, payload=payload, valid=valid))
 
     def top_k(self, view: str, k: int, *, component: str | None = None,
               index: tuple = (), generation: int | None = None,
               snapshot: Snapshot | None = None) -> ReadResult:
         """Top-``k`` live keys by one payload-plane entry: data =
         dict(keys=[k, nk], values=[k], valid=[k])."""
-        snap = self._resolve(snapshot, generation)
-        v = self._view(snap, view)
-        keys, values, valid = lookup_mod.top_k(
-            v, int(k), component=component, index=tuple(index))
-        return ReadResult(view, "top_k", snap.generation,
-                          dict(keys=keys, values=values, valid=valid))
+        with tracing.span("fivm.read"):
+            snap = self._resolve(snapshot, generation)
+            v = self._view(snap, view)
+            keys, values, valid = lookup_mod.top_k(
+                v, int(k), component=component, index=tuple(index))
+            return ReadResult(view, "top_k", snap.generation,
+                              dict(keys=keys, values=values, valid=valid))
 
     # ----------------------------------------------------------- telemetry
     def stats(self) -> dict:

@@ -241,7 +241,8 @@ def test_viewserver_stats_schema():
     assert [e["generation"] for e in seg] == [1, 2, 3]
     assert all(set(e) == {"segment", "n_steps", "admit_s", "dispatch_s",
                           "save_s", "audit_s", "publish_s", "generation",
-                          "straggler", "straggler_baseline"} for e in seg)
+                          "straggler", "straggler_baseline", "counts"}
+               for e in seg)
     name = sorted(server.registry.latest().views)[0]
     server.point(name, np.zeros((2, len(eng.views[name].schema)), np.int32))
     assert server.stats()["generation_lag"] == 0
