@@ -63,7 +63,7 @@ BATCH = 1024
 N_ROUNDS = 4
 #: backends the 1,024-tuple plans must resolve to on the chip
 PALLAS_BACKENDS = frozenset({"onehot", "onehot_dedup", "compact",
-                             "fused_pallas"})
+                             "fused_pallas", "fused_compact"})
 #: engine state (views + stored base) the housing deployment must hold
 MIN_STATE_BYTES = 1 << 30
 #: f32 bound on the housing root: a sum of 2^22 per-postcode products,

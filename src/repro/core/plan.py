@@ -233,9 +233,11 @@ class FusedChain(PlanOp):
     into one megakernel dispatch (``repro.kernels.ring_fused``): every
     gathered payload plane and lifted ring component stays in VMEM across
     the chain, the ring product runs as one fused flat formula, and the
-    terminal ⊎ scatters with per-tile dedup instead of the sort/rank
-    prepass.  Legality is decided at plan time (:func:`fuse_trigger_ops`);
-    the recorded ``reads``/``writes`` keep the chain transparent to the
+    terminal ⊎ scatters with per-tile dedup — over the whole view, or,
+    where the terminal ScatterAccum's hint is ``compact``, over the
+    batch's ranked keys before a B-row add into the view.  Legality is
+    decided at plan time (:func:`fuse_trigger_ops`); the recorded
+    ``reads``/``writes`` keep the chain transparent to the
     collective-placement and CSE passes, and ``vmem_bytes`` is the tile
     model's footprint bound (golden-plan tests pin it)."""
 
@@ -1242,7 +1244,10 @@ def _run_fused_chain(chain: FusedChain, delta: BatchedDelta, views: Mapping,
     * **megakernel** (TPU real / interpret) — gather/lift sources
       accumulate as flat ``(plane [Sg, d], ids [B])`` pairs; the whole
       product + ⊎ runs through one ``ring_fused.fused_apply`` dispatch at
-      the terminal scatter, source planes resident in VMEM.
+      the terminal scatter, source planes resident in VMEM.  The
+      terminal's backend hint picks the sweep of the whole view or,
+      past the onehot/compact crossover, the compact ⊎ over the
+      batch's ranked keys.
     * **flat-XLA** (CPU/GPU) — sources gather as per-component payload
       dicts (``view.gather``; no flat-plane concats at all), the running
       product is one ``Ring.mul`` per hop (``ring_mul_flat`` is its flat

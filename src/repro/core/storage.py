@@ -310,11 +310,13 @@ def _insert_ids(table: jnp.ndarray, ids: jnp.ndarray):
     return table, out_slot, placed
 
 
-def _rank_ids(ids: jnp.ndarray):
-    """Sort/rank key dedup (the PR-2 compaction prepass): per-row rank into
-    the distinct-id list + the distinct ids themselves (EMPTY-padded).
-    Sentinel ids (< 0) collapse into one EMPTY rank.  ``_insert_ids``
-    requires distinct ids — every insert path resolves slots per *rank*."""
+def rank_ids(ids: jnp.ndarray):
+    """Sort/rank key dedup (the compaction prepass): per-row rank into the
+    distinct-id list + the distinct ids themselves (EMPTY-padded).
+    Sentinel ids (< 0) collapse into one EMPTY rank, which sorts first.
+    ``_insert_ids`` requires distinct ids — every insert path resolves
+    slots per *rank* — and the compact ⊎ (``scatter_ops``, ``ring_fused``)
+    sums duplicates over the ranks and scatters the distinct ids."""
     B = ids.shape[0]
     order = jnp.argsort(ids)
     sid = ids[order]
@@ -328,7 +330,7 @@ def _rank_ids(ids: jnp.ndarray):
 
 def _dedup_ids(ids: jnp.ndarray, vals: jnp.ndarray):
     """Distinct ids (EMPTY-padded) + per-id summed value rows."""
-    rank, uniq = _rank_ids(ids)
+    rank, uniq = rank_ids(ids)
     sums = jnp.zeros((ids.shape[0], vals.shape[1]), vals.dtype).at[rank].add(
         vals)
     return uniq, sums
@@ -497,7 +499,7 @@ class SparseRelation:
         from repro.kernels import scatter_ops
 
         ids = linear_ids(keys, self._domains)
-        rank, uniq = _rank_ids(ids)
+        rank, uniq = rank_ids(ids)
         table, slots, placed = _insert_ids(self.table, uniq)
         target = jnp.where(placed, slots, EMPTY)[rank]
         plane = flatten_payload(self.ring, self.payload, (self.capacity,))
@@ -516,7 +518,7 @@ class SparseRelation:
         fused kernel accumulates duplicates per tile.  Overflow rows (table
         full) map to EMPTY and drop."""
         ids = linear_ids(keys, self._domains)
-        rank, uniq = _rank_ids(ids)
+        rank, uniq = rank_ids(ids)
         table, slots, placed = _insert_ids(self.table, uniq)
         target = jnp.where(placed, slots, EMPTY)[rank]
         return table, target

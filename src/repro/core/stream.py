@@ -403,6 +403,8 @@ def prepare_stream(
         with tracing.span("fivm.admit.plans"):
             plans = verified(tuple(plan_for(r, b)
                                    for r, b in zip(pattern, buckets)))
+            _count_fused_chains(plans[j % period]
+                                for j in range(len(stream)))
         return PreparedStream(
             mode="scan" if period == 1 else "rounds",
             rel_order=rel_order,
@@ -435,6 +437,7 @@ def prepare_stream(
                                          np.int32))
     with tracing.span("fivm.admit.plans"):
         plans = verified(tuple(plan_for(r, bucket) for r in rel_order))
+        _count_fused_chains(plans[rel_order.index(r)] for r in sched)
     return PreparedStream(
         mode="switch",
         rel_order=rel_order,
@@ -449,6 +452,22 @@ def prepare_stream(
         backend_sig=backend_sig,
         fusion_sig=fusion_sig,
     )
+
+
+def _count_fused_chains(batch_plans) -> None:
+    """Count the fused chains that a stream's batches run
+    (``fused_chains``) and those of them lowered through the compact ⊎
+    (``fused_chains_compact``), one plan per batch; a segment's stats
+    entry takes both (``counts``)."""
+    from repro.kernels import ring_fused
+
+    for plan in batch_plans:
+        for op in plan.ops:
+            if isinstance(op, plan_mod.FusedChain):
+                tracing.count("fused_chains")
+                tracing.count("fused_chains_compact", int(
+                    ring_fused.resolve_backend(op.ops[-1].backend)
+                    in ring_fused.COMPACT_BACKENDS))
 
 
 class StreamExecutor:

@@ -14,10 +14,10 @@ lift relations alike) is a ``(plane [Sg, d], ids [B])`` pair, the degree-m
 (c, s, Q) ring product runs as one fused flat formula
 (:func:`ring_mul_flat`, replacing the per-bilinear-term einsum soup of
 ``Ring.mul``), and the final ⊎ goes through the one-hot path with
-*per-tile dedup* (``ring_scatter.tile_dedup``) instead of the global
-sort/rank compaction prepass.
+*per-tile dedup* (``ring_scatter.tile_dedup``).
 
-Three lowerings, chosen by :func:`resolve_backend`:
+Lowerings, chosen by :func:`resolve_backend` from the hint the plan
+resolved for the chain's terminal ScatterAccum:
 
 * ``fused_pallas`` — the TPU megakernel: grid ``(S/bs, B/bk)``, source
   planes ride whole in VMEM (the plan-time legality pass bounds them by
@@ -25,7 +25,14 @@ Three lowerings, chosen by :func:`resolve_backend`:
   tile gathers via one-hot MXU contractions, ring-multiplies in registers,
   dedups in-tile, and accumulates into the revisited output block.  The
   ``[B, d]`` intermediate never exists in HBM.
-* ``fused_interpret`` — the same kernel in Pallas interpret mode (CI).
+* ``fused_compact`` — the same megakernel for a target view past the
+  onehot/compact crossover (the hint ``compact``): the batch's out ids
+  are ranked once (``scatter_ops.compact_ranks``), the kernel accumulates
+  over the local ranks into a zero plane of B rows (grid ``(B/bs, B/bk)``
+  instead of ``(S/bs, B/bk)``), and at most B distinct rows are added into
+  the view in place.  The view never enters the kernel.
+* ``fused_interpret`` / ``fused_compact_interpret`` — the same in Pallas
+  interpret mode (CI).
 * ``fused_xla`` — flat ``take``/multiply/``.at[].add`` over the same
   planes (CPU/GPU): still one fused pipeline per chain instead of one
   einsum per bilinear term and one scatter per ring component.
@@ -43,6 +50,7 @@ from jax.experimental import pallas as pl
 
 from .ring_scatter import (EXACT, _col, _col_spec, _iota_cols, _row,
                            _row_spec, per_device, tile_dedup)
+from .scatter_ops import compact_ranks
 
 #: largest gathered-source plane (rows) a fused chain keeps whole in VMEM;
 #: chains gathering from bigger planes stay unfused (op-by-op fallback)
@@ -56,7 +64,11 @@ VMEM_BUDGET = 8 * 1024 * 1024
 BLOCK_S = 128
 BLOCK_K = 256
 
-BACKENDS = ("fused_xla", "fused_pallas", "fused_interpret")
+BACKENDS = ("fused_xla", "fused_pallas", "fused_interpret",
+            "fused_compact", "fused_compact_interpret")
+
+#: the lowerings that ⊎ through the compact prepass instead of the sweep
+COMPACT_BACKENDS = ("fused_compact", "fused_compact_interpret")
 
 
 # ---------------------------------------------------------------------------
@@ -235,15 +247,18 @@ def _fused_pallas(view_plane, out_ids, vals, sources, spec, *, block_s: int,
 # ---------------------------------------------------------------------------
 def resolve_backend(hint: str | None = None) -> str:
     """Lowering for a fused chain: the plan bakes its ScatterAccum's
-    resolved scatter-backend hint in; ``*_interpret`` hints (CI forcing)
-    select the interpret-mode megakernel, TPU gets the real one, and
-    everything else takes the flat-XLA lowering."""
+    resolved scatter-backend hint in.  A ``compact`` hint (the target view
+    is past the onehot/compact crossover) takes the compact megakernel;
+    other ``*_interpret`` hints (CI forcing) the interpret-mode sweep; TPU
+    gets the real sweep, and everything else the flat-XLA lowering."""
     if hint in BACKENDS:
         return hint
+    if hint == "compact_interpret":
+        return "fused_compact_interpret"
     if hint and hint.endswith("_interpret"):
         return "fused_interpret"
     if jax.default_backend() == "tpu":
-        return "fused_pallas"
+        return "fused_compact" if hint == "compact" else "fused_pallas"
     return "fused_xla"
 
 
@@ -260,6 +275,7 @@ def fused_apply(view_plane, out_ids, vals, sources, spec, *,
     ring).  ``out_ids`` rows < 0 drop.  Returns the new ``[S, d]`` f32
     plane."""
     b = resolve_backend(backend)
+    interpret = b.endswith("_interpret")
     if b == "fused_xla":
         cur = vals
         for plane, ids in sources:
@@ -269,6 +285,15 @@ def fused_apply(view_plane, out_ids, vals, sources, spec, *,
         safe = jnp.where(out_ids < 0, S, out_ids)
         return view_plane.astype(jnp.float32).at[safe].add(
             cur.astype(jnp.float32), mode="drop")
+    if b in COMPACT_BACKENDS:
+        S, d = view_plane.shape
+        B = out_ids.shape[0]
+        rank, uniq = compact_ranks(out_ids, S)
+        sums = _fused_pallas(jnp.zeros((B, d), jnp.float32), rank, vals,
+                             tuple(sources), spec, block_s=block_s,
+                             block_k=block_k, interpret=interpret)
+        return view_plane.astype(jnp.float32).at[uniq].add(sums,
+                                                            mode="drop")
     return _fused_pallas(view_plane, out_ids, vals, tuple(sources), spec,
                          block_s=block_s, block_k=block_k,
-                         interpret=(b == "fused_interpret"))
+                         interpret=interpret)

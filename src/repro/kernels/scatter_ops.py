@@ -45,7 +45,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.storage import (comp_width, flatten_payload, linear_ids,
-                                unflatten_payload)
+                                rank_ids, unflatten_payload)
 
 from . import ref
 from .ring_scatter import gather_mul_scatter as _gms_pallas
@@ -222,6 +222,16 @@ def _scatter_add_flat(view, seg_ids, values, backend: str,
     return out[:S, :d]
 
 
+def compact_ranks(seg_ids, num_segments: int):
+    """The compact ⊎'s prepass (:func:`repro.core.storage.rank_ids`):
+    each row's local rank, and the view row of each rank.  Unused rank
+    slots and the padding rank (ids < 0, which rank first) point at
+    ``num_segments``, out of range, so ``.at[uniq].add(..., mode="drop")``
+    drops them."""
+    rank, uniq = rank_ids(seg_ids.astype(jnp.int32))
+    return rank, jnp.where(uniq < 0, num_segments, uniq)
+
+
 def _compact_scatter(view, seg_ids, values, backend: str, *, block_s: int,
                      block_d: int, block_k: int):
     """Key-dedup + local accumulate: sort the batch's ids, rank distinct
@@ -231,17 +241,7 @@ def _compact_scatter(view, seg_ids, values, backend: str, *, block_s: int,
     an out-of-range target, so they drop."""
     S, d = view.shape
     B = seg_ids.shape[0]
-    seg_ids = seg_ids.astype(jnp.int32)
-    order = jnp.argsort(seg_ids)
-    sid = seg_ids[order]
-    first = jnp.concatenate(
-        [jnp.ones((1,), bool), sid[1:] != sid[:-1]])
-    rank_sorted = jnp.cumsum(first.astype(jnp.int32)) - 1  # [B]
-    rank = jnp.zeros((B,), jnp.int32).at[order].set(rank_sorted)
-    # unique id per rank slot; unused slots (and the padding segment) point
-    # out of range and are dropped by the final scatter
-    uniq = jnp.full((B,), S, jnp.int32).at[rank].set(
-        jnp.where(seg_ids < 0, S, seg_ids))
+    rank, uniq = compact_ranks(seg_ids, S)
     inner = {"compact": "pallas", "compact_interpret": "interpret",
              "compact_xla": "jnp"}[backend]
     if inner == "jnp":

@@ -327,6 +327,38 @@ def test_fused_eager_interpreter_matches_unfused():
                 np.asarray(fused.views[name].payload[comp]))
 
 
+@pytest.mark.parametrize("pc,lowering", [(8192, "fused_compact"),
+                                          (64, "fused_pallas")])
+def test_fused_chain_lowering_follows_the_crossover(monkeypatch, plain_env,
+                                                    pc, lowering):
+    """On the chip a fused chain's ⊎ follows the hint its terminal
+    ScatterAccum resolved: past the onehot/compact crossover
+    (``max(4096, 8·B)``) the compact ⊎, at or below it the sweep.  The
+    plan itself is the one every other ⊎ gets; only its lowering moves."""
+    from repro.kernels import ring_fused
+
+    ring = sum_ring()
+    doms = dict(A=pc, B=4, C=4)
+    rels = {"R": ("B", "A"), "S": ("C", "A")}
+    q = Query(relations=rels, free_vars=(), ring=ring, domains=doms,
+              lifts={"B": ("value",)})
+    db = {n: DenseRelation(sch, ring, {"v": jnp.ones(
+              tuple(doms[v] for v in sch), jnp.float32)})
+          for n, sch in rels.items()}
+    eng = IVMEngine.build(q, db, var_order=chain(["A"], {"A": [["B"], ["C"]]}),
+                          storage="dense")
+    upd = COOUpdate(("B", "A"), jnp.zeros((16, 2), jnp.int32),
+                    {"v": jnp.ones((16,), jnp.float32)})
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    plan = eng.trigger_plan("R", upd)
+    # the first chain lifts B into the view over A (at pc = 64 a second
+    # one gathers the S side's view into the root)
+    chains = [op for op in plan.ops if isinstance(op, plan_mod.FusedChain)]
+    term = chains[0].ops[-1]
+    assert term.backend == ("compact" if pc > 4096 else "onehot")
+    assert ring_fused.resolve_backend(term.backend) == lowering
+
+
 # ---------------------------------------------------------------------------
 # plan cache
 # ---------------------------------------------------------------------------

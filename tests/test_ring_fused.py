@@ -7,9 +7,9 @@ pieces to the unfused primitives they replace:
 
 * :func:`ring_mul_flat` against ``Ring.mul``'s einsum path — bit-identical
   float association on integer-valued f32 payloads, scalar and degree-m;
-* :func:`fused_apply` (flat-XLA and interpret-mode Pallas lowerings)
-  against the compose-by-hand ``take`` / ``Ring.mul`` / ``.at[].add``
-  oracle, with duplicate out-ids and padding rows;
+* :func:`fused_apply` (flat-XLA and the interpret-mode Pallas sweep and
+  compact lowerings) against the compose-by-hand ``take`` / ``Ring.mul``
+  / ``.at[].add`` oracle, with duplicate out-ids and padding rows;
 * the plan-time VMEM model's determinism (golden plans pin its numbers).
 """
 import jax.numpy as jnp
@@ -21,7 +21,7 @@ from repro.core import DegreeMRing, sum_ring
 from repro.core import storage
 from repro.kernels import ring_fused
 
-FUSED_BACKENDS = ("fused_xla", "fused_interpret")
+FUSED_BACKENDS = ("fused_xla", "fused_interpret", "fused_compact_interpret")
 
 
 def _int_floats(rng, shape, lo=-4, hi=5):
@@ -150,7 +150,9 @@ def test_fused_apply_duplicates_and_padding(backend):
     np.testing.assert_array_equal(np.asarray(got_p), np.asarray(exp))
 
 
-def test_fused_apply_multi_tile_interpret():
+@pytest.mark.parametrize("backend", ["fused_interpret",
+                                     "fused_compact_interpret"])
+def test_fused_apply_multi_tile_interpret(backend):
     """Shapes past one (block_s, block_k) tile: revisited output blocks
     accumulate across batch tiles."""
     rng = np.random.default_rng(9)
@@ -163,9 +165,37 @@ def test_fused_apply_multi_tile_interpret():
            jnp.asarray(rng.integers(0, 33, B).astype(np.int32)))
     exp = _oracle(view, out_ids, vals, [src], ring)
     got = ring_fused.fused_apply(view, out_ids, vals, [src], ("scalar",),
-                                 backend="fused_interpret",
-                                 block_s=32, block_k=64)
+                                 backend=backend, block_s=32, block_k=64)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(exp))
+
+
+def test_compact_fused_apply_large_target():
+    """A 2^16-row view and a 256-row batch: the compact lowering adds into
+    the rows the batch touches, exactly as the ``.at[].add`` oracle does,
+    and leaves every other row bit for bit as it was."""
+    rng = np.random.default_rng(16)
+    ring = sum_ring()
+    S, B = 1 << 16, 256
+    # dyadic values: every sum is exact in f32, in any order
+    view = jnp.asarray((rng.integers(-1000, 1000, size=(S, 1))
+                        + 0.25 * rng.integers(0, 4, size=(S, 1)))
+                       .astype(np.float32))
+    vals = _int_floats(rng, (B, 1))
+    hot = rng.integers(0, S, size=B // 4)
+    out_ids = np.where(rng.random(B) < 0.5, rng.choice(hot, size=B),
+                       rng.integers(0, S, size=B)).astype(np.int32)
+    out_ids[-5:] = -1  # padding rows drop
+    src = (_int_floats(rng, (8, 1)),
+           jnp.asarray(rng.integers(0, 8, B).astype(np.int32)))
+    got = np.asarray(ring_fused.fused_apply(
+        view, jnp.asarray(out_ids), vals, [src], ("scalar",),
+        backend="fused_compact_interpret"))
+    exp = np.asarray(_oracle(view, jnp.asarray(out_ids), vals, [src], ring))
+    touched = np.zeros(S, bool)
+    touched[out_ids[out_ids >= 0]] = True
+    assert 0 < touched.sum() <= B
+    np.testing.assert_array_equal(got[touched], exp[touched])
+    np.testing.assert_array_equal(got[~touched], np.asarray(view)[~touched])
 
 
 # ---------------------------------------------------------------------------
@@ -178,10 +208,23 @@ def test_chain_vmem_model_deterministic_and_monotone():
     assert ring_fused.chain_vmem_bytes((100, 200), 130) > a
 
 
-def test_resolve_backend_hints():
+def test_resolve_backend_hints(monkeypatch):
     assert ring_fused.resolve_backend("fused_interpret") == "fused_interpret"
     assert ring_fused.resolve_backend("onehot_interpret") == "fused_interpret"
+    assert ring_fused.resolve_backend(
+        "onehot_dedup_interpret") == "fused_interpret"
+    assert ring_fused.resolve_backend(
+        "compact_interpret") == "fused_compact_interpret"
+    assert ring_fused.resolve_backend("fused_compact") == "fused_compact"
     import jax
     if jax.default_backend() != "tpu":
         assert ring_fused.resolve_backend(None) == "fused_xla"
         assert ring_fused.resolve_backend("jnp") == "fused_xla"
+        assert ring_fused.resolve_backend("compact") == "fused_xla"
+    # on the chip the plan's scatter hint picks sweep or compact ⊎
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ring_fused.resolve_backend("compact") == "fused_compact"
+    for hint in (None, "onehot", "onehot_dedup"):
+        assert ring_fused.resolve_backend(hint) == "fused_pallas", hint
+    assert ring_fused.resolve_backend(
+        "onehot_interpret") == "fused_interpret"

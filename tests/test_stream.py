@@ -529,3 +529,95 @@ def test_executor_does_not_clobber_engine_or_db():
     assert not np.array_equal(
         np.asarray(eng.result().payload["v"]),
         np.asarray(fresh.result().payload["v"]))
+
+
+# ---------------------------------------------------------------------------
+# fused chains into a view past the onehot/compact crossover
+# ---------------------------------------------------------------------------
+STAR_DOMS = dict(A=8192, B=4, C=4)
+
+
+def _star_engine(storage):
+    """A two-relation star on ``A`` whose R trigger fuses Lift(B) →
+    Marginalize(B) → ⊎ into an 8,192-row view: past the crossover
+    ``max(4096, 8·B)`` for B = 16, so the plan's hint there is compact."""
+    rng = np.random.default_rng(5)
+    ring = sum_ring()
+    rels = {"R": ("B", "A"), "S": ("C", "A")}
+    q = Query(relations=rels, free_vars=(), ring=ring, domains=STAR_DOMS,
+              lifts={"B": ("value",)})
+    db = {n: DenseRelation(sch, ring, {"v": jnp.asarray(
+              rng.integers(0, 2, size=tuple(STAR_DOMS[v] for v in sch))
+              .astype(np.float32))}) for n, sch in rels.items()}
+    return IVMEngine.build(q, db, var_order=chain(["A"], {"A": [["B"], ["C"]]}),
+                           storage=storage)
+
+
+def _star_stream(q, n, B=16, seed=8):
+    rng = np.random.default_rng(seed)
+    out = []
+    for i in range(n):
+        rel = "RS"[i % 2]
+        sch = q.relations[rel]
+        # a few hot keys so the batch carries duplicate out ids
+        keys = np.stack([rng.integers(0, 4 if v == "A" and i % 3 == 0
+                                      else STAR_DOMS[v], size=B)
+                         for v in sch], 1).astype(np.int32)
+        out.append((rel, COOUpdate(sch, jnp.asarray(keys), {"v": jnp.asarray(
+            rng.integers(-2, 3, B).astype(np.float32))})))
+    return out
+
+
+@pytest.mark.parametrize("backend,share", [("compact_interpret", 1.0),
+                                           ("onehot_interpret", 0.0)])
+@pytest.mark.parametrize("storage", ["dense", "sparse"])
+def test_compact_fused_chain_replays_unfused(storage, backend, share):
+    """Fusion on, terminal ⊎ through the compact lowering
+    (``compact_interpret``) or the sweep (``onehot_interpret``): the
+    segmented stream replays bit-identically to fusion off, and the
+    segments count their fused chains and the compact ones among them
+    (what ``fused_compact_share`` reads)."""
+    from benchmarks.chip.metrics import fused_compact_share
+    from repro.core import plan as plan_mod
+    from repro.kernels import scatter_ops
+    from repro.serve import SnapshotRegistry
+
+    with plan_mod.use_fusion("off"):
+        oracle = _star_engine(storage)
+        stream = _star_stream(oracle.query, 8)
+        ex_off = StreamExecutor(
+            oracle, registry=SnapshotRegistry(segment_updates=4))
+        ex_off.run(stream)
+    with plan_mod.use_fusion("on"), \
+            scatter_ops.use_backend(backend):
+        fused = _star_engine(storage)
+        ex = StreamExecutor(fused,
+                            registry=SnapshotRegistry(segment_updates=4))
+        ex.run(stream)
+    segs = ex.last_segment_stats
+    assert len(segs) == 2
+    for s in segs:
+        # four batches a segment, two of them R's one fused chain each
+        assert s["counts"]["fused_chains"] == 2
+        assert s["counts"]["fused_chains_compact"] == 2 * share
+    assert fused_compact_share.read(_WindowRun(segs)) == share
+    assert fused_compact_share.read(
+        _WindowRun(ex_off.last_segment_stats)) is None
+    for name in oracle.views:
+        a, b = oracle.views[name], fused.views[name]
+        da = a.to_dense() if isinstance(a, SparseRelation) else a
+        db = b.to_dense() if isinstance(b, SparseRelation) else b
+        for comp in da.payload:
+            np.testing.assert_array_equal(
+                np.asarray(da.payload[comp]), np.asarray(db.payload[comp]),
+                err_msg=f"{name}/{comp} [{storage}]")
+
+
+class _WindowRun:
+    """The slice of the chip benchmark's ``Run`` a segment metric reads."""
+
+    def __init__(self, segments):
+        self._segments = list(segments)
+
+    def window_segments(self):
+        return self._segments

@@ -114,7 +114,27 @@ def test_fused_chain_compiles_for_v5e(one_chip, spec, S, src_rows):
     assert "tpu_custom_call" in hlo
 
 
-def test_kernels_compile_on_a_sharded_v5e_mesh(topo):
+def test_compact_house_chain_compiles_for_v5e(one_chip):
+    """The House chain (lift h2 of 8 rows into the 2^22-postcode view)
+    through the compact ⊎: the kernel's grid spans the batch, not the
+    view, so no [S, 128] plane is padded, swept or sliced; the program's
+    temporaries stay far under the sweep's (4.0 GiB)."""
+    spec = ("scalar",)
+
+    def fn(view, out_ids, vals, lift, lift_ids):
+        return ring_fused.fused_apply(view, out_ids, vals, [(lift, lift_ids)],
+                                      spec, backend="fused_compact")
+
+    compiled = jax.jit(fn, donate_argnums=0).lower(
+        _sds(one_chip, (HOUSING_PC, 1)), _sds(one_chip, (B,), jnp.int32),
+        _sds(one_chip, (B, 1)), _sds(one_chip, (8, 1)),
+        _sds(one_chip, (B,), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
+
+
+@pytest.mark.parametrize("backend", ["fused_pallas", "fused_compact"])
+def test_kernels_compile_on_a_sharded_v5e_mesh(topo, backend):
     """A view split over a 2x2 mesh, as the plan-sharded stream executor
     places it: under the mesh each Mosaic kernel runs per device
     (``ring_scatter.per_device``); JAX refuses to partition one itself."""
@@ -129,7 +149,7 @@ def test_kernels_compile_on_a_sharded_v5e_mesh(topo):
                                           block_s=128, block_d=128,
                                           block_k=512)
         return ring_fused.fused_apply(a, ids, vals, [(lift, lift_ids)],
-                                      ("scalar",), backend="fused_pallas")
+                                      ("scalar",), backend=backend)
 
     shapes = (_sds(split, (8192, 1)), _sds(rep, (B,), jnp.int32),
               _sds(rep, (B, 1)), _sds(rep, (8, 1)),
